@@ -1136,6 +1136,8 @@ def main() -> None:
     ap.add_argument("--splits", type=int, default=60)
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():
         if args.only and name != args.only:

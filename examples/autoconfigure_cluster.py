@@ -9,10 +9,12 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.autoconfig import autoconfigure
 
 
 def main():
+    enable_compile_cache()
     for arch, budget in (("gemma3-1b", None),
                          ("deepseek-7b", 0.8),
                          ("kimi-k2-1t-a32b", None)):

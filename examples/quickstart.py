@@ -13,12 +13,14 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Hub, JobRepo, RuntimeDataStore
 from repro.core.features import RuntimeData
 from repro.workloads import spark_emul as W
 
 
 def main():
+    enable_compile_cache()
     # --- maintainers publish job repos with shared runtime data ----------
     hub = Hub()
     for job in ("sort", "grep", "kmeans"):

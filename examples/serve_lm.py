@@ -10,12 +10,14 @@ import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import smoke_config
 from repro.modeling import model as M
 from repro.serve.serve_step import greedy_generate
 
 
 def main():
+    enable_compile_cache()
     for kv_dtype in ("", "int8"):
         cfg = smoke_config("gemma3-1b", kv_cache_dtype=kv_dtype)
         params = M.init_params(cfg, jax.random.PRNGKey(0))

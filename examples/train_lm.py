@@ -11,11 +11,13 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.train import run as train_run
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

@@ -45,13 +45,12 @@ Cache keys
     and falls back to the numerically-reference single-device path
     otherwise.
 
-``_gbm_kernel_executable(interpret)``
+``_gbm_kernel_executable()``
     The Pallas boosted-ensemble inference kernel
-    (``repro.kernels.gbm_predict``) jitted once per interpret mode.  Batched
-    predictions of GBM-selected predictors route through it on TPU backends
-    (``C3O_GBM_KERNEL=auto``, the default); set ``on``/``interpret``/``off``
-    to force the kernel, the interpreted kernel (CPU correctness path), or
-    the jnp scan fallback.
+    (``repro.kernels.gbm_predict``), jitted once.  On a TPU backend every
+    batched prediction of a GBM-selected predictor runs through it; on
+    any other backend GBM predicts through the cached jnp executable.
+    The choice follows the backend alone: there is no switch.
 
 ``JobRepo.predictor_for`` (see ``repro.core.hub``)
     fitted predictors cached per
@@ -93,16 +92,30 @@ from repro.core.models.api import ModelSpec
 # --------------------------------------------------------------------------
 
 
+def _full_precision(fn):
+    """``fn`` traced with f32 matmuls at full precision.  A TPU's default
+    runs them as one bf16 pass, which costs the normal equations and
+    linear predictions of the model pool about three significant digits;
+    every engine executable pins it so a chip answers like the CPU."""
+
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+
+    return traced
+
+
 @functools.lru_cache(maxsize=None)
 def fit_executable(spec: ModelSpec):
     """Cached jitted ``spec.fit``: (X, y, w, aux) -> params."""
-    return jax.jit(spec.fit)
+    return jax.jit(_full_precision(spec.fit))
 
 
 @functools.lru_cache(maxsize=None)
 def predict_executable(spec: ModelSpec):
     """Cached jitted ``spec.predict``: (params, X, aux) -> yhat."""
-    return jax.jit(spec.predict)
+    return jax.jit(_full_precision(spec.predict))
 
 
 @functools.lru_cache(maxsize=None)
@@ -127,7 +140,7 @@ def val_executable(spec: ModelSpec):
         ape = err / jnp.maximum(jnp.abs(y_te), 1e-9)
         return (ape * valid).sum() / cnt, (err * valid).sum() / cnt
 
-    return jax.jit(_val)
+    return jax.jit(_full_precision(_val))
 
 
 def _bucket(n: int, lo: int = 32) -> int:
@@ -229,7 +242,7 @@ def cv_executable(spec: ModelSpec):
             (resid * resid * valid).sum() / cnt - mu * mu, 0.0))
         return mape, mu, sigma
 
-    return jax.jit(_cv)
+    return jax.jit(_full_precision(_cv))
 
 
 # --------------------------------------------------------------------------
@@ -259,8 +272,6 @@ def cv_executable_sharded(spec: ModelSpec, n_devices: int):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
-
     mesh = _cv_mesh(n_devices)
 
     def _shard(X, y, W, fold_idx, valid, aux):
@@ -283,12 +294,12 @@ def cv_executable_sharded(spec: ModelSpec, n_devices: int):
         sigma = jnp.sqrt(jnp.maximum(r2_s / cnt - mu * mu, 0.0))
         return mape, mu, sigma
 
-    fn = shard_map(_shard, mesh=mesh,
-                   in_specs=(P(), P(), P("cv"), P("cv"), P("cv"), P()),
-                   out_specs=(P(), P(), P()), check_vma=False)
+    fn = jax.shard_map(_shard, mesh=mesh,
+                       in_specs=(P(), P(), P("cv"), P("cv"), P("cv"), P()),
+                       out_specs=(P(), P(), P()), check_vma=False)
     # donating on CPU only triggers "donation not implemented" warnings
     donate = () if jax.default_backend() == "cpu" else (2,)
-    return jax.jit(fn, donate_argnums=donate)
+    return jax.jit(_full_precision(fn), donate_argnums=donate)
 
 
 def _cv_shard_devices() -> int:
@@ -329,7 +340,9 @@ def cache_clear() -> None:
 # --------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=2)
-def _gbm_kernel_executable(interpret: bool):
+def _gbm_kernel_executable(interpret: bool = False):
+    """The jitted kernel path; ``interpret=True`` only where a test asks
+    for the interpreted kernel directly."""
     from repro.kernels.gbm_predict import gbm_predict as pallas_gbm
 
     def run(X, feat, thr, leaf, f0, y_scale):
@@ -343,28 +356,24 @@ def _gbm_kernel_executable(interpret: bool):
     return jax.jit(run)
 
 
-def _gbm_kernel_mode() -> str:
-    mode = os.environ.get("C3O_GBM_KERNEL", "auto").lower()
-    if mode == "auto":
-        return "on" if jax.default_backend() == "tpu" else "off"
-    return mode
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def predict(spec: ModelSpec, params, X, aux) -> jnp.ndarray:
     """Batched prediction through the cached executable for ``spec``.
 
-    GBM predictors route through the Pallas ensemble kernel when enabled
-    (TPU backend, or ``C3O_GBM_KERNEL`` in {on, interpret}); everything else
-    (and the CPU default) uses the cached jnp executable.
+    On a TPU backend GBM predictors run the Pallas ensemble kernel;
+    everything else uses the cached jnp executable.
     """
     Xj = jnp.asarray(X, jnp.float32)
     from repro.core.models.gbm import GBM_SPEC
-    if spec is GBM_SPEC:        # identity, not name: a maintainer model
-        mode = _gbm_kernel_mode()   # re-registered as "gbm" has foreign params
-        if mode in ("on", "interpret"):
-            return _gbm_kernel_executable(mode == "interpret")(
-                Xj, params.feat, params.thr, params.leaf, params.f0,
-                params.y_scale)
+    # identity, not name: a maintainer model re-registered as "gbm" has
+    # foreign params
+    if spec is GBM_SPEC and _on_tpu():
+        return _gbm_kernel_executable()(
+            Xj, params.feat, params.thr, params.leaf, params.f0,
+            params.y_scale)
     return predict_executable(spec)(params, Xj, aux)
 
 

@@ -51,12 +51,10 @@ class C3OPredictor:
         every dispatch downstream."""
         return self.fit(data.X, data.y, row_weight=row_weight)
 
-    def fit(self, X: np.ndarray, y: np.ndarray,
-            row_weight: Optional[np.ndarray] = None) -> "C3OPredictor":
-        """``row_weight`` (fractional, [n]) down-weights suspect rows in
-        CV selection AND the final fit — the trust plane derives it from
-        contributor reputation (``RuntimeDataStore.row_weights``).  None
-        keeps the exact unweighted path (byte-identical numerics)."""
+    def cv_inputs(self, X: np.ndarray, y: np.ndarray,
+                  row_weight: Optional[np.ndarray] = None):
+        """The exact ``engine.cv_select`` inputs ``fit`` uses:
+        (X, y, folds, row weights), padded when ``pad_rows`` is set."""
         X = np.asarray(X, np.float64)
         y = np.asarray(y, np.float64)
         n = len(y)
@@ -81,6 +79,15 @@ class C3OPredictor:
             w = np.zeros(b, np.float64)
             w[:n] = 1.0 if row_weight is None else row_weight
             X, y = Xp, yp
+        return X, y, folds, w
+
+    def fit(self, X: np.ndarray, y: np.ndarray,
+            row_weight: Optional[np.ndarray] = None) -> "C3OPredictor":
+        """``row_weight`` (fractional, [n]) down-weights suspect rows in
+        CV selection AND the final fit — the trust plane derives it from
+        contributor reputation (``RuntimeDataStore.row_weights``).  None
+        keeps the exact unweighted path (byte-identical numerics)."""
+        X, y, folds, w = self.cv_inputs(X, y, row_weight)
         specs = [get_model(name) for name in self.model_names]
         best, mapes, mu, sigma = engine.cv_select(specs, X, y, folds,
                                                   row_weight=w)

@@ -95,6 +95,5 @@ def quantized_psum(x, axis_names: Tuple[str, ...], mesh, in_spec: P,
             out = out[:-pad]
         return out.reshape(xs.shape)
 
-    from repro.distributed.sharding import shard_map
-    return shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=in_spec, check_vma=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=in_spec, check_vma=False)(x)

@@ -21,16 +21,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):           # jax >= 0.5 top-level export
-    shard_map = jax.shard_map
-else:                                   # older releases: experimental home,
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        # ...where check_vma was still called check_rep
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
 # logical name -> tuple of preferred physical axes (tried in order, all used)
 DEFAULT_RULES = {
     "batch": ("pod", "data"),

@@ -46,7 +46,7 @@ def mamba_scan(u, dt, A, B_in, C_in, h0=None, *, chunk=64, d_block=512):
                           d_block=d_block, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("row_block",))
-def gbm_predict(X, feat, thr, leaf, f0, y_scale=1.0, *, row_block=256):
+@jax.jit
+def gbm_predict(X, feat, thr, leaf, f0, y_scale=1.0):
     return _gp.gbm_predict(X, feat, thr, leaf, f0, y_scale,
-                           row_block=row_block, interpret=_interpret())
+                           interpret=_interpret())

@@ -22,7 +22,6 @@ import jax           # noqa: E402
 from repro.configs import SHAPES, get_config, list_archs, supports_shape  # noqa: E402
 from repro.distributed import sharding  # noqa: E402
 from repro.launch import specs as SP    # noqa: E402
-from repro.launch.compat import cost_analysis_dict  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.analytic import analytic_cost  # noqa: E402
 from repro.launch.roofline import (collective_bytes, model_flops,  # noqa: E402
@@ -68,7 +67,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         compiled = _compile_cell(cfg, shape, mesh)
         t_compile = time.time() - t0
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         peak = int(getattr(mem, "argument_size_in_bytes", 0)
                    + getattr(mem, "temp_size_in_bytes", 0))
 

@@ -2,29 +2,17 @@
 
 ``make_production_mesh`` is a function (never a module-level constant) so that
 importing this module does not touch jax device state.
-
-``mesh_axis_types`` shims the ``jax.sharding.AxisType`` API across JAX
-versions: older releases (< 0.5) have neither the enum nor the
-``axis_types=`` kwarg on ``jax.make_mesh``, where every axis is implicitly
-Auto — the behavior we request explicitly on newer releases.
 """
 from __future__ import annotations
 
 import jax
 
 
-def mesh_axis_types(n_axes: int) -> dict:
-    """kwargs for ``jax.make_mesh``: explicit Auto axis types when the
-    running JAX supports them, empty (implicit Auto) otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh_auto(shape, axes, **kw):
-    """``jax.make_mesh`` with Auto axis types on every JAX version."""
-    return jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)), **kw)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -198,7 +198,7 @@ def moe_apply_ep(cfg: ModelConfig, p, x) -> Tuple[jax.Array, jax.Array]:
             y = jax.lax.psum(y, "model")
         return y, aux
 
-    y, aux = sharding.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, w_spec),
         out_specs=(x_spec, P()),

@@ -496,6 +496,8 @@ def main(argv=None) -> None:
                     help="comma-separated emulated jobs to publish")
     ap.add_argument("--max-batch", type=int, default=256)
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     async def run():
         gw = _demo_gateway(tuple(j for j in args.jobs.split(",") if j))

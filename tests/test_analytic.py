@@ -12,7 +12,6 @@ from repro.configs import smoke_config
 from repro.configs.base import ShapeConfig
 from repro.launch import specs as SP
 from repro.launch.analytic import analytic_cost
-from repro.launch.compat import cost_analysis_dict
 from repro.train import train_step as TS
 
 
@@ -23,7 +22,7 @@ def _hlo_flops(cfg, shape):
         (shape.global_batch, shape.seq_len), jnp.int32)}
     state = SP.abstract_state(cfg)
     comp = jax.jit(TS.make_train_step(cfg)).lower(state, batch).compile()
-    return cost_analysis_dict(comp).get("flops", 0.0)
+    return comp.cost_analysis().get("flops", 0.0)
 
 
 def test_scan_undercount_regression():
@@ -35,8 +34,8 @@ def test_scan_undercount_regression():
                                 length=K)[0]
         return f
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    f1 = cost_analysis_dict(jax.jit(make(1)).lower(x).compile())["flops"]
-    f8 = cost_analysis_dict(jax.jit(make(8)).lower(x).compile())["flops"]
+    f1 = jax.jit(make(1)).lower(x).compile().cost_analysis()["flops"]
+    f8 = jax.jit(make(8)).lower(x).compile().cost_analysis()["flops"]
     # trip count ignored (only loop-bookkeeping flops differ)
     assert f8 < f1 * 1.01
 

@@ -150,13 +150,24 @@ def test_predictor_for_refits_only_on_accepted_contribution():
 # --------------------------------------------------------------------------
 
 def test_gbm_kernel_routing_matches_jnp_path(monkeypatch):
+    """On a TPU backend ``engine.predict`` sends GBM predictions through
+    the Pallas kernel.  The test steers that choice by standing in for
+    the backend check and runs the kernel in interpret mode."""
     rng = np.random.default_rng(4)
     X = rng.uniform(1, 10, (24, 2))
     y = 20 + 5 * X[:, 1] / X[:, 0] + rng.normal(0, 0.5, 24)
     fm = FittedModel(get_model("gbm"), X, y)
     Xq = rng.uniform(1, 10, (40, 2))
-    monkeypatch.setenv("C3O_GBM_KERNEL", "off")
-    ref = fm.predict(Xq)
-    monkeypatch.setenv("C3O_GBM_KERNEL", "interpret")
+    ref = fm.predict(Xq)                        # CPU backend: jnp path
+    kernel = engine._gbm_kernel_executable
+    calls = []
+
+    def interpreted():
+        calls.append(1)
+        return kernel(interpret=True)
+
+    monkeypatch.setattr(engine, "_on_tpu", lambda: True)
+    monkeypatch.setattr(engine, "_gbm_kernel_executable", interpreted)
     out = fm.predict(Xq)
+    assert calls, "GBM prediction on a TPU backend must take the kernel"
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)

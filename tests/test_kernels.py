@@ -154,6 +154,33 @@ def test_gbm_predict_kernel_hypothesis(n, d, T):
                      n_trees=T)
     ref = gbm_jnp(params, jnp.asarray(X))
     out = gbm_predict(jnp.asarray(X), params.feat, params.thr, params.leaf,
-                      params.f0, params.y_scale, row_block=64, interpret=True)
+                      params.f0, params.y_scale, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4,
                                rtol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def gbm_ensemble():
+    """The pool GBM (200 trees, depth 3, log target) fitted on 4 features."""
+    from repro.core.models.gbm import gbm_fit
+    rng = np.random.default_rng(7)
+    X = rng.uniform(0, 10, (96, 4)).astype(np.float32)
+    y = (5 + X[:, 0] * 2 + np.sin(X[:, 1]) * X[:, 2]).astype(np.float32)
+    orders = jnp.asarray(np.argsort(X, axis=0).T)
+    return gbm_fit(jnp.asarray(X), jnp.asarray(y), jnp.ones(96), orders,
+                   n_trees=200, depth=3, log_target=True)
+
+
+@pytest.mark.parametrize("n", [1, 7, 255, 256, 1000, 1792, 4096])
+def test_gbm_predict_kernel_row_counts(gbm_ensemble, n):
+    """Every padding case — under one lane group, one block of up to 1024
+    rows, several 1024-row blocks with a ragged tail — routes each row as
+    the jnp path does and adds the same f32 leaves in the same order, so
+    the raw ensemble sums are identical."""
+    from repro.core.models.gbm import gbm_predict as gbm_jnp
+    p = gbm_ensemble
+    X = jnp.asarray(np.random.default_rng(n).uniform(-1, 11, (n, 4)),
+                    jnp.float32)
+    raw = gbm_predict(X, p.feat, p.thr, p.leaf, p.f0, interpret=True)
+    ref = gbm_jnp(p._replace(y_scale=jnp.asarray(1.0)), X)
+    np.testing.assert_array_equal(np.asarray(raw), np.asarray(ref))
