@@ -37,6 +37,7 @@ from repro.api.types import (ERR_BAD_REQUEST, ERR_INTERNAL, ERR_TIMEOUT,
                              ModelErrorsResult, PredictRequest, PredictResult,
                              Response, SearchRequest, SearchResult,
                              TrustStateRequest, TrustStateResult)
+from repro.core import trace
 from repro.core.features import RuntimeData
 from repro.core.market import MarketError, PriceBook
 from repro.core.service import ConfigurationService
@@ -625,17 +626,19 @@ class AsyncHubGateway:
                 # tick's envelopes are built here in one tight loop —
                 # per-request coroutines just hand the finished Response
                 # through
-                _, src, conf = self.gateway._resolve(
-                    _job, contexts.shape[1] + 1)
-                choices = self.gateway._service(
-                    src or _job, _seed).choose_cluster_batch(contexts, t_max)
+                with trace.span("hub.service"):
+                    _, src, conf = self.gateway._resolve(
+                        _job, contexts.shape[1] + 1)
+                    svc = self.gateway._service(src or _job, _seed)
+                choices = svc.choose_cluster_batch(contexts, t_max)
                 return [Response.success(
                             ChooseResult.from_choice(c, src, conf))
                         for c in choices]
 
             lane = BatchLane(dispatch, width=repo.schema.n_features - 1,
                              max_batch=self.max_batch, tick_s=self.tick_s,
-                             timeout_s=self.timeout_s)
+                             timeout_s=self.timeout_s,
+                             name=self._lane_name(job, key[1], seed))
             lane.start()
             self._lanes[key] = lane
             while len(self._lanes) > self.MAX_LANES:
@@ -685,7 +688,9 @@ class AsyncHubGateway:
 
             lane = BatchLane(dispatch, width=repo.schema.n_features,
                              max_batch=self.max_batch, tick_s=self.tick_s,
-                             timeout_s=self.timeout_s)
+                             timeout_s=self.timeout_s,
+                             name=self._lane_name(job, key[1], seed,
+                                                  machine_type))
             lane.start()
             self._predict_lanes[key] = lane
             while len(self._predict_lanes) > self.MAX_LANES:
@@ -693,6 +698,15 @@ class AsyncHubGateway:
                 self._stop_lane(old)
         self._predict_lanes.move_to_end(key)
         return lane
+
+    def _lane_name(self, job: str, src: str, seed: int,
+                   machine: str = "") -> str:
+        """Display name of the lane serving ``job`` (on ``machine``, for
+        a predict lane) from ``src``'s models at ``seed``."""
+        name = f"{job}@{machine}" if machine else job
+        if src != job:
+            name = f"{name}<-{src}"
+        return name if seed == self.gateway.seed else f"{name}#seed={seed}"
 
     @property
     def lane_stats(self) -> Dict[str, ServeStats]:
@@ -702,21 +716,9 @@ class AsyncHubGateway:
         default seed (display names; routing uses tuples).  Predict lanes
         for superseded store versions are already evicted, so one name
         maps to one live lane."""
-        out = {}
-        for (job, src, seed), lane in self._lanes.items():
-            name = job if src == job else f"{job}<-{src}"
-            if seed != self.gateway.seed:
-                name = f"{name}#seed={seed}"
-            out[name] = lane.stats
-        for (job, src, machine, seed,
-             _ver), lane in self._predict_lanes.items():
-            name = f"{job}@{machine}"
-            if src != job:
-                name = f"{name}<-{src}"
-            if seed != self.gateway.seed:
-                name = f"{name}#seed={seed}"
-            out[name] = lane.stats
-        return out
+        return {lane.name: lane.stats
+                for lanes in (self._lanes, self._predict_lanes)
+                for lane in lanes.values()}
 
     # ------------------------- request path -------------------------------
     async def predict(self, req) -> Response[PredictResult]:
@@ -724,19 +726,21 @@ class AsyncHubGateway:
         (job, machine, seed, store-version) lane into ONE
         ``predictor.predict`` dispatch per tick; multi-row requests are
         already a batch and dispatch inline (sync path, same envelope)."""
-        req, _, err = self.gateway._admit(req, PredictRequest)
-        if err is not None:
-            return err
         try:
-            if len(req.X) != 1:
+            with trace.span("gateway.admit"):
+                req, _, err = self.gateway._admit(req, PredictRequest)
+                if err is not None:
+                    return err
+                row = req.X[0] if len(req.X) == 1 else None
+                if row is not None:
+                    lane = self._predict_lane(
+                        req.job, req.machine_type, req.seed,
+                        len(row) if hasattr(row, "__len__") else None)
+            if row is None:
                 # already admitted: dispatch directly, not via the sync
                 # entry point (re-admission would double-charge quota and
                 # refuse the unwrapped request on an auth-enabled gateway)
                 return self.gateway._respond(self.gateway._predict, req)
-            row = req.X[0]
-            lane = self._predict_lane(
-                req.job, req.machine_type, req.seed,
-                len(row) if hasattr(row, "__len__") else None)
             return await lane.submit(row, None)
         except UnknownJobError as e:
             return Response.failure(
@@ -755,22 +759,26 @@ class AsyncHubGateway:
         # admission (auth + quota) happens HERE, before the request is
         # enqueued on any lane: a rate-limited contributor never occupies
         # micro-batch capacity
-        req, _, err = self.gateway._admit(req, ChooseRequest)
-        if err is not None:
-            return err
         try:
-            if req.zones is not None or req.purchase_options is not None:
+            with trace.span("gateway.admit"):
+                req, _, err = self.gateway._admit(req, ChooseRequest)
+                if err is not None:
+                    return err
                 # placement-constrained choices cannot share a lane's
                 # packed dispatch (a lane batches per (job, seed) with
-                # ONE placement universe per tick) — dispatch inline,
+                # ONE placement universe per tick) — they dispatch inline,
                 # already admitted, same envelope as the sync path.  A
                 # bad constraint therefore answers a typed bad_request
                 # without ever creating a lane.
+                inline = req.zones is not None \
+                    or req.purchase_options is not None
+                ctx = req.context
+                if not inline:
+                    lane = self._lane(
+                        req.job, req.seed,
+                        len(ctx) + 1 if hasattr(ctx, "__len__") else None)
+            if inline:
                 return self.gateway._respond(self.gateway._choose, req)
-            ctx = req.context
-            lane = self._lane(
-                req.job, req.seed,
-                len(ctx) + 1 if hasattr(ctx, "__len__") else None)
             # submit() canonicalizes the row; the lane dispatch already
             # wrapped the answer in a Response envelope
             return await lane.submit(ctx, req.t_max)

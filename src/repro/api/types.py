@@ -293,7 +293,8 @@ class LaneSnapshot:
     """One micro-batch lane's serving counters: dispatched requests,
     ticks, realized mean batch, and latency percentiles (milliseconds,
     enqueue-to-answer, from the lane's bounded reservoir; NaN until the
-    lane has dispatched)."""
+    lane has dispatched).  ``spans`` holds the lane's span totals as
+    ``(name, count, total_s, self_s)`` rows (``repro.core.trace``)."""
     lane: str
     requests: int
     batches: int
@@ -301,6 +302,8 @@ class LaneSnapshot:
     p50_ms: float
     p95_ms: float
     p99_ms: float
+    spans: Tuple[Tuple[str, int, float, float], ...] = field(
+        default=(), metadata={"omit_default": True})
 
 
 @dataclass(frozen=True, slots=True)
@@ -309,7 +312,9 @@ class StatsResult:
     and latency percentiles (milliseconds, receive-to-response, bounded
     reservoir) plus one ``LaneSnapshot`` per live micro-batch lane —
     choose lanes are named ``job``, predict lanes ``job@machine`` (both
-    with a ``#seed=N`` suffix off the default seed)."""
+    with a ``#seed=N`` suffix off the default seed).  ``spans`` holds
+    the span totals of everything outside a lane (the edge, set-up fits,
+    inline paths) as ``(name, count, total_s, self_s)`` rows."""
     requests: int
     errors: int
     in_flight: int
@@ -318,6 +323,8 @@ class StatsResult:
     p95_ms: float
     p99_ms: float
     lanes: Tuple[LaneSnapshot, ...]
+    spans: Tuple[Tuple[str, int, float, float], ...] = field(
+        default=(), metadata={"omit_default": True})
 
 
 @dataclass(frozen=True, slots=True)

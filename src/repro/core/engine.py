@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.models.api import ModelSpec
 
 # --------------------------------------------------------------------------
@@ -364,17 +365,26 @@ def predict(spec: ModelSpec, params, X, aux) -> jnp.ndarray:
     """Batched prediction through the cached executable for ``spec``.
 
     On a TPU backend GBM predictors run the Pallas ensemble kernel;
-    everything else uses the cached jnp executable.
+    everything else uses the cached jnp executable.  The enqueue is the
+    ``engine.dispatch`` span (a new row count lowers inside it).
     """
-    Xj = jnp.asarray(X, jnp.float32)
-    from repro.core.models.gbm import GBM_SPEC
-    # identity, not name: a maintainer model re-registered as "gbm" has
-    # foreign params
-    if spec is GBM_SPEC and _on_tpu():
-        return _gbm_kernel_executable()(
-            Xj, params.feat, params.thr, params.leaf, params.f0,
-            params.y_scale)
-    return predict_executable(spec)(params, Xj, aux)
+    with trace.span("engine.dispatch", model=spec.name, rows=len(X)):
+        Xj = jnp.asarray(X, jnp.float32)
+        from repro.core.models.gbm import GBM_SPEC
+        # identity, not name: a maintainer model re-registered as "gbm"
+        # has foreign params
+        if spec is GBM_SPEC and _on_tpu():
+            return _gbm_kernel_executable()(
+                Xj, params.feat, params.thr, params.leaf, params.f0,
+                params.y_scale)
+        return predict_executable(spec)(params, Xj, aux)
+
+
+def to_host(result) -> np.ndarray:
+    """A device result as a float64 host array: the wait on the device,
+    timed as the ``engine.sync`` span."""
+    with trace.span("engine.sync"):
+        return np.asarray(result, np.float64)
 
 
 # --------------------------------------------------------------------------
@@ -510,8 +520,7 @@ def machine_grid_runtimes(predictors: Dict[str, object],
     for m, pred in predictors.items():
         names.append(m)
         pending.append(_predict_rows(pred, rows))           # async dispatch
-    t = np.stack([np.asarray(p, np.float64)
-                  .reshape(len(scaleouts), len(contexts)).T
+    t = np.stack([to_host(p).reshape(len(scaleouts), len(contexts)).T
                   for p in pending])
     return names, np.maximum(t, 0.0)
 

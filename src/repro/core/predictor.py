@@ -19,7 +19,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.core import engine
+from repro.core import engine, trace
 from repro.core.models.api import get_model
 
 DEFAULT_MODELS = ("ernest", "gbm", "bom", "ogb")
@@ -89,14 +89,16 @@ class C3OPredictor:
         keeps the exact unweighted path (byte-identical numerics)."""
         X, y, folds, w = self.cv_inputs(X, y, row_weight)
         specs = [get_model(name) for name in self.model_names]
-        best, mapes, mu, sigma = engine.cv_select(specs, X, y, folds,
-                                                  row_weight=w)
+        with trace.span("engine.cv"):
+            best, mapes, mu, sigma = engine.cv_select(specs, X, y, folds,
+                                                      row_weight=w)
         self.cv_mape.update(mapes)
         self.selected = best
         self.mu = mu
         self.sigma = sigma
         from repro.core.models.api import FittedModel
-        self._fitted = FittedModel(get_model(best), X, y, w)
+        with trace.span("engine.fit"):
+            self._fitted = FittedModel(get_model(best), X, y, w)
         return self
 
     # ------------------- warm-start persistence ---------------------------
@@ -142,7 +144,7 @@ class C3OPredictor:
         return self._fitted.predict_device(np.asarray(X, np.float64))
 
     def predict(self, X) -> np.ndarray:
-        return self._fitted.predict(np.asarray(X, np.float64))
+        return engine.to_host(self.predict_device(X))
 
     def predict_with_error(self, X) -> Tuple[np.ndarray, float, float]:
         """(predictions, mu, sigma) — sigma from CV residuals (paper §IV-B)."""

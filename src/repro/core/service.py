@@ -33,7 +33,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from repro.core import engine
+from repro.core import engine, trace
 from repro.core.configurator import (ClusterChoice, confidence_margin,
                                      validate_confidence)
 from repro.core.market import MarketError, PriceBook, validate_prices
@@ -185,20 +185,21 @@ class ConfigurationService:
         names, t, bound, cost, bott = self.score_cluster_grid(contexts)
         C, S = len(contexts), len(self.scaleouts)
         K = len(names) * S
-        # [C, M*S] flat grids, machine-major (ties resolve to the first
-        # machine in dict order, matching choose_machine_type)
-        tf = np.transpose(t, (1, 0, 2)).reshape(C, K)
-        bf = np.transpose(bound, (1, 0, 2)).reshape(C, K)
-        cf = np.transpose(cost, (1, 0, 2)).reshape(C, K)
-        of = np.transpose(bott, (1, 0, 2)).reshape(C, K)
-        idx = self._select(cf, bf, of, t_max, C)
-        out = []
-        for c, j in enumerate(idx):
-            m, s = int(j) // S, int(j) % S
-            out.append(ClusterChoice(names[m], int(self.scaleouts[s]),
-                                     float(tf[c, j]), float(bf[c, j]),
-                                     float(cf[c, j]), bool(of[c, j])))
-        return out
+        with trace.span("service.select"):
+            # [C, M*S] flat grids, machine-major (ties resolve to the
+            # first machine in dict order, matching choose_machine_type)
+            tf = np.transpose(t, (1, 0, 2)).reshape(C, K)
+            bf = np.transpose(bound, (1, 0, 2)).reshape(C, K)
+            cf = np.transpose(cost, (1, 0, 2)).reshape(C, K)
+            of = np.transpose(bott, (1, 0, 2)).reshape(C, K)
+            idx = self._select(cf, bf, of, t_max, C)
+            out = []
+            for c, j in enumerate(idx):
+                m, s = int(j) // S, int(j) % S
+                out.append(ClusterChoice(names[m], int(self.scaleouts[s]),
+                                         float(tf[c, j]), float(bf[c, j]),
+                                         float(cf[c, j]), bool(of[c, j])))
+            return out
 
     def _choose_market(self, contexts: np.ndarray,
                        t_max: Union[None, float, np.ndarray],
@@ -216,24 +217,25 @@ class ConfigurationService:
         P = len(placements)
         K = len(names) * P * S
         t4 = np.broadcast_to(t[:, None], et.shape)
-        # [C, M*P*S] flat grids ([M, P, C, S] -> [C, M, P, S])
-        tf = np.transpose(t4, (2, 0, 1, 3)).reshape(C, K)
-        bf = np.transpose(bound, (2, 0, 1, 3)).reshape(C, K)
-        nf = np.transpose(naive, (2, 0, 1, 3)).reshape(C, K)
-        af = np.transpose(adj, (2, 0, 1, 3)).reshape(C, K)
-        of = np.transpose(bott, (2, 0, 1, 3)).reshape(C, K)
-        idx = self._select(af, bf, of, t_max, C)
-        out = []
-        for c, j in enumerate(idx):
-            j = int(j)
-            m, p, s = j // (P * S), (j // S) % P, j % S
-            out.append(ClusterChoice(
-                names[m], int(self.scaleouts[s]), float(tf[c, j]),
-                float(bf[c, j]), float(nf[c, j]), bool(of[c, j]),
-                zone=placements[p].zone,
-                purchase_option=placements[p].option,
-                expected_cost_usd=float(af[c, j])))
-        return out
+        with trace.span("service.select"):
+            # [C, M*P*S] flat grids ([M, P, C, S] -> [C, M, P, S])
+            tf = np.transpose(t4, (2, 0, 1, 3)).reshape(C, K)
+            bf = np.transpose(bound, (2, 0, 1, 3)).reshape(C, K)
+            nf = np.transpose(naive, (2, 0, 1, 3)).reshape(C, K)
+            af = np.transpose(adj, (2, 0, 1, 3)).reshape(C, K)
+            of = np.transpose(bott, (2, 0, 1, 3)).reshape(C, K)
+            idx = self._select(af, bf, of, t_max, C)
+            out = []
+            for c, j in enumerate(idx):
+                j = int(j)
+                m, p, s = j // (P * S), (j // S) % P, j % S
+                out.append(ClusterChoice(
+                    names[m], int(self.scaleouts[s]), float(tf[c, j]),
+                    float(bf[c, j]), float(nf[c, j]), bool(of[c, j]),
+                    zone=placements[p].zone,
+                    purchase_option=placements[p].option,
+                    expected_cost_usd=float(af[c, j])))
+            return out
 
     def choose_cluster(self, context_row: np.ndarray,
                        t_max: Optional[float] = None) -> ClusterChoice:

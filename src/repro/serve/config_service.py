@@ -30,6 +30,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.configurator import ClusterChoice
 from repro.core.service import ConfigurationService
 
@@ -94,10 +95,13 @@ class ServeStats:
     forever).  ``requests`` counts DISPATCHED requests only — enqueue-
     rejected submissions never reach a batch.  ``latency`` is a bounded
     ring-buffer reservoir of per-request latencies (enqueue to answer),
-    so p50/p95/p99 come from the server side without unbounded lists."""
+    so p50/p95/p99 come from the server side without unbounded lists.
+    ``spans`` holds the span totals (``repro.core.trace``) of whatever
+    these stats describe: a lane's own, or the process's for the edge."""
     requests: int = 0
     batches: int = 0
     latency: LatencyReservoir = field(default_factory=LatencyReservoir)
+    spans: trace.Recorder = field(default_factory=trace.Recorder)
 
     def record_batch(self, size: int) -> None:
         self.requests += size
@@ -157,12 +161,18 @@ class BatchLane:
     ``LaneTimeoutError`` while the worker moves on to the next tick — a
     wedged dispatch costs its own callers a typed ``timeout`` envelope,
     not the lane.
+
+    Each tick records into the lane's own ``stats.spans``: ``lane.wait``
+    per request (enqueue to the start of its tick), ``lane.tick`` per
+    group (pack + dispatch + fan-out), the ``lane.pack`` span, and every
+    span the dispatch itself opens (``repro.core.trace``).
     """
 
     def __init__(self, dispatch: Callable, *, width: Optional[int] = None,
                  max_batch: int = 256, tick_s: float = 0.0,
-                 timeout_s: Optional[float] = None):
+                 timeout_s: Optional[float] = None, name: str = ""):
         self.dispatch = dispatch
+        self.name = name                   # display name (stats, traces)
         self.width = width
         self.max_batch = max_batch
         self.tick_s = tick_s
@@ -218,6 +228,11 @@ class BatchLane:
 
     # ------------------------- worker loop --------------------------------
     async def _run(self) -> None:
+        # the worker's spans, its dispatches' included, land on the lane
+        with trace.recording(self.stats.spans):
+            await self._serve()
+
+    async def _serve(self) -> None:
         batch = []
         try:
             while True:
@@ -239,50 +254,62 @@ class BatchLane:
                 for entry in batch:
                     groups.setdefault(len(entry[0]), []).append(entry)
                 for group in groups.values():
+                    t_tick = time.monotonic()
+                    for entry in group:
+                        trace.interval("lane.wait", t_tick - entry[3])
                     try:
-                        # the pack itself can raise (non-numeric content in
-                        # a width-correct tuple): that failure belongs to
-                        # this group's callers, not the worker — the lane
-                        # must survive any single bad payload
-                        contexts = np.empty((len(group), len(group[0][0])),
-                                            np.float64)
-                        t_max = np.empty(len(group), np.float64)
-                        for i, (ctx, tm, _, _) in enumerate(group):
-                            contexts[i] = ctx
-                            t_max[i] = tm
-                        results = await self._dispatch_group(contexts, t_max)
-                    except (asyncio.TimeoutError, TimeoutError):
-                        # deadline missed: fail THIS group with the typed
-                        # lane error and keep serving — the dispatch thread
-                        # finishes on the executor in the background, its
-                        # result discarded (the futures are already failed)
-                        err = LaneTimeoutError(
-                            f"micro-batch dispatch exceeded its "
-                            f"{self.timeout_s:g}s deadline "
-                            f"({len(group)} request(s) affected)")
-                        for _, _, fut, _ in group:
-                            if not fut.done():
-                                fut.set_exception(err)
-                        continue
-                    except Exception as e:           # fan the failure out
-                        for _, _, fut, _ in group:
-                            if not fut.done():
-                                fut.set_exception(e)
-                        continue
-                    self.stats.record_batch(len(group))
-                    now = time.monotonic()
-                    for (_, _, fut, t0), result in zip(group, results):
-                        # per-request latency: enqueue to answer, into the
-                        # bounded reservoir (dispatched requests only,
-                        # like the request counter)
-                        self.stats.record_latency(now - t0)
-                        if not fut.done():
-                            fut.set_result(result)
+                        await self._tick(group)
+                    finally:
+                        trace.interval("lane.tick", time.monotonic() - t_tick)
                 batch = []
         finally:
             for _, _, fut, _ in batch:  # cancelled mid-batch: don't strand
                 if not fut.done():
                     fut.cancel()
+
+    async def _tick(self, group) -> None:
+        """Pack, dispatch and answer one width group."""
+        try:
+            # the pack itself can raise (non-numeric content in a
+            # width-correct tuple): that failure belongs to this group's
+            # callers, not the worker — the lane must survive any single
+            # bad payload
+            with trace.span("lane.pack", lane=self.name,
+                            tick=self.stats.batches):
+                contexts = np.empty((len(group), len(group[0][0])),
+                                    np.float64)
+                t_max = np.empty(len(group), np.float64)
+                for i, (ctx, tm, _, _) in enumerate(group):
+                    contexts[i] = ctx
+                    t_max[i] = tm
+            results = await self._dispatch_group(contexts, t_max)
+        except (asyncio.TimeoutError, TimeoutError):
+            # deadline missed: fail THIS group with the typed lane error
+            # and keep serving — the dispatch thread finishes on the
+            # executor in the background, its result discarded (the
+            # futures are already failed)
+            err = LaneTimeoutError(
+                f"micro-batch dispatch exceeded its "
+                f"{self.timeout_s:g}s deadline "
+                f"({len(group)} request(s) affected)")
+            for _, _, fut, _ in group:
+                if not fut.done():
+                    fut.set_exception(err)
+            return
+        except Exception as e:           # fan the failure out
+            for _, _, fut, _ in group:
+                if not fut.done():
+                    fut.set_exception(e)
+            return
+        self.stats.record_batch(len(group))
+        now = time.monotonic()
+        for (_, _, fut, t0), result in zip(group, results):
+            # per-request latency: enqueue to answer, into the bounded
+            # reservoir (dispatched requests only, like the request
+            # counter)
+            self.stats.record_latency(now - t0)
+            if not fut.done():
+                fut.set_result(result)
 
     async def _dispatch_group(self, contexts, t_max):
         """One group's dispatch, under the lane deadline if configured.
@@ -295,8 +322,15 @@ class BatchLane:
             return self.dispatch(contexts, t_max)
         loop = asyncio.get_running_loop()
         return await asyncio.wait_for(
-            loop.run_in_executor(None, self.dispatch, contexts, t_max),
+            loop.run_in_executor(None, self._dispatch_on_thread, contexts,
+                                 t_max),
             self.timeout_s)
+
+    def _dispatch_on_thread(self, contexts, t_max):
+        # the dispatch's spans land on the lane, on a stack of their own:
+        # the worker may tick again while an abandoned dispatch runs on
+        with trace.recording(self.stats.spans):
+            return self.dispatch(contexts, t_max)
 
 
 class AsyncConfigService:

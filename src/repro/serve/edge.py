@@ -57,6 +57,7 @@ from repro.api.types import (API_VERSION, ERR_BAD_REQUEST, ERR_INTERNAL,
                              ContributeRequest, HealthResult, LaneSnapshot,
                              ModelErrorsRequest, PredictRequest, Response,
                              SearchRequest, StatsResult, TrustStateRequest)
+from repro.core import trace
 from repro.serve.config_service import ServeStats
 
 #: request-envelope type expected by each POST /v1/<op> endpoint
@@ -69,6 +70,11 @@ OPS: Dict[str, type] = {
     "trust_state": TrustStateRequest,
     "compact": CompactRequest,
 }
+_OP_OF = {t: op for op, t in OPS.items()}
+
+#: endpoints that are not API operations: a request to one of them does
+#: not count as the edge holding a request
+INTROSPECTION = ("/healthz", "/stats")
 
 #: HTTP status for each typed error code (ok envelopes are 200); the
 #: body is ALWAYS a codec-encoded Response — the status is advisory for
@@ -102,16 +108,28 @@ class HubEdgeApp:
     a typed ``bad_request`` envelope with HTTP 413 before the gateway is
     touched.  HTTP-level latency (receive to response) lands in a
     bounded ``ServeStats`` reservoir served back on ``GET /stats``
-    alongside every micro-batch lane's snapshot."""
+    alongside every micro-batch lane's snapshot.
+
+    Spans (``repro.core.trace``): ``edge.read`` (the body; the host has
+    parsed the head before the app is called), ``edge.decode``,
+    ``edge.encode`` and ``edge.write`` around the request's phases;
+    ``edge.request.<op>`` from app entry to response sent;
+    ``edge.in_flight`` for each stretch with an API request in the edge
+    and the traced ``edge.idle`` for each stretch with none.  They, and
+    everything else outside a lane, land on the process recorder, which
+    ``stats.spans`` serves."""
 
     def __init__(self, gateway: AsyncHubGateway, *,
                  max_body: int = 1 << 20):
         self.gateway = gateway
         self.max_body = int(max_body)
-        self.stats = ServeStats()
+        self.stats = ServeStats(spans=trace.PROCESS)
         self.errors = 0                    # responses with error envelopes
         self.in_flight = 0
         self.draining = False
+        self._api = 0                      # API requests in the edge
+        self._busy_since = 0.0
+        self._idle: Optional[trace.Open] = None
 
     # ------------------------- ASGI entry ---------------------------------
     async def __call__(self, scope, receive, send) -> None:
@@ -120,11 +138,19 @@ class HubEdgeApp:
             return
         if scope["type"] != "http":        # pragma: no cover - ws etc.
             raise RuntimeError(f"unsupported ASGI scope {scope['type']!r}")
+        with trace.recording(self.stats.spans):
+            await self._serve(scope, receive, send)
+
+    async def _serve(self, scope, receive, send) -> None:
         t0 = time.monotonic()
+        api = scope["path"] not in INTROSPECTION
+        if api:
+            self._api_enter(t0)
         self.in_flight += 1
+        op = ""
         try:
             try:
-                status, resp = await self._handle(scope, receive)
+                status, resp, op = await self._handle(scope, receive)
             except asyncio.CancelledError:
                 raise
             except Exception as e:         # noqa: BLE001 — never a raw 500
@@ -132,16 +158,38 @@ class HubEdgeApp:
                     ERR_INTERNAL, f"{type(e).__name__}: {e}")
             if not resp.ok:
                 self.errors += 1
-            body = codec.encode(resp).encode("ascii")
-            await send({"type": "http.response.start", "status": status,
-                        "headers": [(b"content-type", b"application/json"),
-                                    (b"content-length",
-                                     str(len(body)).encode("ascii"))]})
-            await send({"type": "http.response.body", "body": body})
+            with trace.span("edge.encode"):
+                body = codec.encode(resp).encode("ascii")
+            with trace.span("edge.write"):
+                await send({"type": "http.response.start", "status": status,
+                            "headers": [(b"content-type",
+                                         b"application/json"),
+                                        (b"content-length",
+                                         str(len(body)).encode("ascii"))]})
+                await send({"type": "http.response.body", "body": body})
         finally:
             self.in_flight -= 1
             self.stats.record_batch(1)
-            self.stats.record_latency(time.monotonic() - t0)
+            t1 = time.monotonic()
+            self.stats.record_latency(t1 - t0)
+            if api:
+                self._api_exit(op, t0, t1)
+
+    def _api_enter(self, now: float) -> None:
+        if self._api == 0:
+            self._busy_since = now
+            if self._idle is not None:
+                self._idle.close()
+                self._idle = None
+        self._api += 1
+
+    def _api_exit(self, op: str, t0: float, now: float) -> None:
+        if op:
+            trace.interval(f"edge.request.{op}", now - t0)
+        self._api -= 1
+        if self._api == 0:
+            trace.interval("edge.in_flight", now - self._busy_since)
+            self._idle = trace.Open("edge.idle")
 
     async def _lifespan(self, receive, send) -> None:
         """Minimal lifespan protocol so uvicorn-style hosts can manage
@@ -171,72 +219,75 @@ class HubEdgeApp:
         await self.gateway.stop()
 
     # ------------------------- request handling ---------------------------
-    async def _handle(self, scope, receive) -> Tuple[int, Response]:
+    async def _handle(self, scope, receive) -> Tuple[int, Response, str]:
+        """(HTTP status, envelope, the API operation served or "")."""
         method = scope["method"]
         path = scope["path"]
         if path == "/healthz":
             if method != "GET":
                 return 405, Response.failure(
                     ERR_BAD_REQUEST, f"{method} not allowed on {path}: "
-                    "use GET")
-            return 200, Response.success(self._health())
+                    "use GET"), ""
+            return 200, Response.success(self._health()), ""
         if path == "/stats":
             if method != "GET":
                 return 405, Response.failure(
                     ERR_BAD_REQUEST, f"{method} not allowed on {path}: "
-                    "use GET")
-            return 200, Response.success(self.snapshot())
+                    "use GET"), ""
+            return 200, Response.success(self.snapshot()), ""
         if self.draining:
             # introspection stays up through the drain; API operations
             # are refused with the typed envelope so clients fail over
             return 503, Response.failure(
                 ERR_SHUTTING_DOWN,
                 "edge is draining for shutdown; retry against another "
-                "replica")
+                "replica"), ""
         op = None
         if path != "/v1":
             if not path.startswith("/v1/"):
                 return 404, Response.failure(
                     ERR_BAD_REQUEST,
                     f"no such endpoint: {path!r} (POST /v1/<op> with op in "
-                    f"{sorted(OPS)}, GET /healthz, GET /stats)")
+                    f"{sorted(OPS)}, GET /healthz, GET /stats)"), ""
             op = path[len("/v1/"):]
             if op not in OPS:
                 return 404, Response.failure(
                     ERR_BAD_REQUEST,
-                    f"unknown operation {op!r} (known: {sorted(OPS)})")
+                    f"unknown operation {op!r} (known: {sorted(OPS)})"), ""
         if method != "POST":
             return 405, Response.failure(
                 ERR_BAD_REQUEST,
                 f"{method} not allowed on {path}: API v1 operations are "
-                "POST")
-        body, overflow = await self._read_body(receive)
+                "POST"), ""
+        with trace.span("edge.read"):
+            body, overflow = await self._read_body(receive)
         if overflow:
             return 413, Response.failure(
                 ERR_BAD_REQUEST,
-                f"request body exceeds the {self.max_body}-byte cap")
+                f"request body exceeds the {self.max_body}-byte cap"), ""
         if body is None:
             return 400, Response.failure(
-                ERR_BAD_REQUEST, "client disconnected mid-body")
+                ERR_BAD_REQUEST, "client disconnected mid-body"), ""
         try:
-            request = codec.decode(body.decode("utf-8"))
+            with trace.span("edge.decode"):
+                request = codec.decode(body.decode("utf-8"))
         except Exception as e:             # noqa: BLE001 — client's bytes
             return 400, Response.failure(
                 ERR_BAD_REQUEST,
-                f"malformed request body: {type(e).__name__}: {e}")
+                f"malformed request body: {type(e).__name__}: {e}"), ""
         inner = request.request if isinstance(request, AuthedRequest) \
             else request
         if op is not None and not isinstance(inner, OPS[op]):
             return 400, Response.failure(
                 ERR_BAD_REQUEST,
                 f"endpoint /v1/{op} expects a {OPS[op].__name__}, got "
-                f"{type(inner).__name__}")
+                f"{type(inner).__name__}"), ""
         if type(inner) not in OPS.values():
             return 400, Response.failure(
                 ERR_BAD_REQUEST,
-                f"not an API v1 request: {type(inner).__name__}")
+                f"not an API v1 request: {type(inner).__name__}"), ""
         resp = await self.gateway.handle_async(request)
-        return self._status(resp), resp
+        return self._status(resp), resp, _OP_OF[type(inner)]
 
     async def _read_body(self, receive) -> Tuple[Optional[bytes], bool]:
         """Accumulate the request body up to ``max_body``; returns
@@ -263,16 +314,17 @@ class HubEdgeApp:
 
     def snapshot(self) -> StatsResult:
         """Server-side serving stats: HTTP-level counters/percentiles
-        plus one snapshot per live micro-batch lane."""
+        plus one snapshot per live micro-batch lane, each with its span
+        totals (the process's on the result itself)."""
         lanes = []
         for name, s in sorted(self.gateway.lane_stats.items()):
             lanes.append(LaneSnapshot(
                 name, s.requests, s.batches, s.mean_batch,
-                _ms(s.p50), _ms(s.p95), _ms(s.p99)))
+                _ms(s.p50), _ms(s.p95), _ms(s.p99), s.spans.snapshot()))
         return StatsResult(self.stats.requests, self.errors, self.in_flight,
                            self.draining, _ms(self.stats.p50),
                            _ms(self.stats.p95), _ms(self.stats.p99),
-                           tuple(lanes))
+                           tuple(lanes), self.stats.spans.snapshot())
 
 
 class EdgeServer:

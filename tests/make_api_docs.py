@@ -32,6 +32,38 @@ _ERROR_SAMPLES = ("error_envelope", "unauthorized_envelope",
                   "shutting_down_envelope")
 
 
+#: the spans ``GET /stats`` can report, and where each is measured
+_SPANS = (
+    ("edge.read", "request body read"),
+    ("edge.decode", "codec decode of the request body"),
+    ("edge.encode", "codec encode of the response envelope"),
+    ("edge.write", "response write and drain"),
+    ("edge.request.<op>", "*counter*: app entry to response sent, per "
+     "API operation"),
+    ("edge.in_flight", "*counter*: each stretch with at least one API "
+     "request in the edge (`/stats` and `/healthz` excluded)"),
+    ("edge.idle", "each stretch with no API request in the edge"),
+    ("gateway.admit", "admission and the lane lookup of a choose or "
+     "single-row predict"),
+    ("lane.wait", "*counter*: a request's wait on its lane, enqueue to "
+     "the start of its tick"),
+    ("lane.tick", "*counter*: one lane tick: pack, dispatch, fan-out"),
+    ("lane.pack", "packing a tick's requests into one batch"),
+    ("hub.service", "resolving the job and its configuration service in "
+     "a choose tick"),
+    ("service.select", "choosing from the scored grid and building the "
+     "choices"),
+    ("engine.dispatch", "one predictor's device enqueue"),
+    ("engine.sync", "waiting for one device result on the host"),
+    ("engine.cv", "a predictor's LOO-CV model selection"),
+    ("engine.fit", "a predictor's final fit"),
+    ("engine.lower", "*counter*: lowering an executable (jaxpr to MLIR), "
+     "charged to the span it happened in"),
+    ("engine.compile", "*counter*: compiling an executable or reading it "
+     "from the compile cache"),
+)
+
+
 def _pretty(wire: str) -> str:
     return json.dumps(json.loads(wire), indent=2, sort_keys=True)
 
@@ -142,6 +174,20 @@ def render() -> str:
     w("```json")
     w(_pretty(golden["stats_response"]))
     w("```")
+    w("")
+    w("The result and each `LaneSnapshot` may also carry `spans`: one")
+    w("`[name, count, total_s, self_s]` row per span name the program has")
+    w("recorded since it started (`repro.core.trace`).  Self time is the")
+    w("total less the spans recorded inside it.  A lane's snapshot holds")
+    w("what its ticks recorded; the result holds everything else (the")
+    w("edge, set-up fits, inline paths).  The key is absent while there")
+    w("are none.  The spans, each also a `c3o.<name>` event in a")
+    w("`jax.profiler` trace unless marked *counter*:")
+    w("")
+    w("| Span | Where |")
+    w("|------|-------|")
+    for name, where in _SPANS:
+        w(f"| `{name}` | {where} |")
     w("")
     w("### Cold-start transfer")
     w("")

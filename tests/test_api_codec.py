@@ -340,6 +340,21 @@ def test_serving_envelope_roundtrip(job, draining, p, requests, mean_batch):
     assert not back.ok and back.error_code == "shutting_down"
 
 
+def test_stats_spans_roundtrip():
+    """``/stats`` span rows (result and lane) round-trip byte-stably, and
+    a snapshot without spans keeps the pre-span wire form."""
+    spans = (("edge.decode", 12, 0.0031, 0.0031),
+             ("engine.cv", 15, 31.5, 24.25))
+    lane = LaneSnapshot("grep", 9, 8, 1.125, 5.5, 7.25, 9.0,
+                        (("lane.wait", 9, 0.0012, 0.0012),))
+    msg = Response.success(StatsResult(12, 0, 1, False, 6.0, 8.0, 9.5,
+                                       (lane,), spans))
+    _assert_roundtrip(msg)
+    assert '"spans"' in codec.encode(msg)
+    assert '"spans"' not in codec.encode(StatsResult(
+        0, 0, 0, False, math.nan, math.nan, math.nan, ()))
+
+
 def test_unencodable_value_raises():
     try:
         codec.encode(object())
