@@ -163,8 +163,8 @@ def choose_machine_type(predictors: Dict[str, C3OPredictor],
     """Fallback machine-type selection (paper §IV-A): cheapest expected cost
     at each machine's best scale-out, using per-machine-type predictors.
 
-    The full (machine x scale-out) grid is dispatched through the engine
-    before the first host sync (one batched predict per machine)."""
+    The full (machine x scale-out) grid is scored through the engine's
+    machine grid (one device program for fitted predictors)."""
     validate_prices(prices, predictors)
     names, _t, cost = engine.machine_grid_costs(predictors, prices,
                                                 scaleouts, context_row)

@@ -69,8 +69,11 @@ overlaps model k's compute with model k+1's dispatch.
 Grid-scored configuration
 -------------------------
 ``score_grid`` evaluates a (scale-out x context-batch) grid in one predictor
-call; ``machine_grid_costs`` stacks that over machine types, dispatching all
-machines before the first sync.  ``Configurator.choose_batch`` turns the
+call.  ``machine_grid_costs`` scores it for every machine type in one device
+program (``grid_executable``, cached per tuple of the machines' ModelSpecs):
+one host-to-device copy of the rows, one dispatch, one transfer back.
+Predictors that expose no fitted model fall back to a dispatch per machine,
+all before the first sync.  ``Configurator.choose_batch`` turns the
 scored grid into per-context choices with vectorized numpy selection —
 semantics identical, choice-for-choice, to the scalar ``choose_scaleout``.
 """
@@ -334,16 +337,17 @@ def cache_clear() -> None:
     cv_executable.cache_clear()
     cv_executable_sharded.cache_clear()
     val_executable.cache_clear()
+    grid_executable.cache_clear()
 
 
 # --------------------------------------------------------------------------
 # Prediction dispatch (with Pallas GBM ensemble routing)
 # --------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=2)
-def _gbm_kernel_executable(interpret: bool = False):
-    """The jitted kernel path; ``interpret=True`` only where a test asks
-    for the interpreted kernel directly."""
+def _gbm_kernel(interpret: bool = False):
+    """The kernel path as a plain function of (X, feat, thr, leaf, f0,
+    y_scale): jitted alone by ``_gbm_kernel_executable``, traced into the
+    grid program by ``grid_executable``."""
     from repro.kernels.gbm_predict import gbm_predict as pallas_gbm
 
     def run(X, feat, thr, leaf, f0, y_scale):
@@ -354,7 +358,14 @@ def _gbm_kernel_executable(interpret: bool = False):
                          jnp.exp(jnp.clip(raw, -30.0, 30.0)),
                          raw * jnp.maximum(y_scale, 1e-12))
 
-    return jax.jit(run)
+    return run
+
+
+@functools.lru_cache(maxsize=2)
+def _gbm_kernel_executable(interpret: bool = False):
+    """The jitted kernel path; ``interpret=True`` only where a test asks
+    for the interpreted kernel directly."""
+    return jax.jit(_gbm_kernel(interpret))
 
 
 def _on_tpu() -> bool:
@@ -378,6 +389,50 @@ def predict(spec: ModelSpec, params, X, aux) -> jnp.ndarray:
                 Xj, params.feat, params.thr, params.leaf, params.f0,
                 params.y_scale)
         return predict_executable(spec)(params, Xj, aux)
+
+
+@functools.lru_cache(maxsize=None)
+def grid_executable(specs: Tuple[ModelSpec, ...], kernel: bool = False,
+                    interpret: bool = False):
+    """Cached jitted scoring of several fitted models on the same rows:
+    (params tuple, aux tuple, X [R, d]) -> [len(specs), R].
+
+    Each model computes what ``predict`` computes for it alone: its
+    ``spec.predict`` at full precision, or with ``kernel`` (a TPU
+    backend) the GBM kernel for ``GBM_SPEC``.  The kernel runs under the
+    name scope ``run``, so its custom calls are named ``run.<n>`` in a
+    device trace, as the standalone executable's are.  Keyed on the spec
+    tuple, so XLA keeps one executable per (specs, row count)."""
+    from repro.core.models.gbm import GBM_SPEC
+    run = _gbm_kernel(interpret) if kernel else None
+
+    def grid(params, aux, X):
+        out = []
+        for spec, p, a in zip(specs, params, aux):
+            if run is not None and spec is GBM_SPEC:
+                with jax.named_scope("run"):
+                    out.append(run(X, p.feat, p.thr, p.leaf, p.f0,
+                                   p.y_scale))
+            else:
+                out.append(spec.predict(p, X, a))
+        return jnp.stack(out)
+
+    return jax.jit(_full_precision(grid))
+
+
+def predict_grid(models: Sequence, X) -> jax.Array:
+    """Every fitted model's predictions on the same rows, [len(models),
+    len(X)], in one device program: one host-to-device copy of ``X`` and
+    one enqueue, the ``engine.dispatch`` span (``model=`` joins the
+    models' names).  ``models`` expose ``spec``, ``params`` and ``aux``
+    (``FittedModel``)."""
+    specs = tuple(m.spec for m in models)
+    with trace.span("engine.dispatch",
+                    model="+".join(s.name for s in specs), rows=len(X)):
+        Xj = jnp.asarray(X, jnp.float32)
+        return grid_executable(specs, _on_tpu())(
+            tuple(m.params for m in models), tuple(m.aux for m in models),
+            Xj)
 
 
 def to_host(result) -> np.ndarray:
@@ -509,20 +564,28 @@ def machine_grid_runtimes(predictors: Dict[str, object],
                           scaleouts: Sequence[int],
                           contexts: np.ndarray
                           ) -> Tuple[List[str], np.ndarray]:
-    """Fused runtime predictions for the (machine x scale-out x context)
-    grid: every machine's grid prediction is dispatched before the first
-    host sync.  Returns (machine names, t [M, C, S]) with runtimes
-    clamped at >= 0 (a negative runtime would make a negative cost win
-    every cheapest-choice selection downstream)."""
+    """Runtime predictions for the (machine x scale-out x context) grid.
+
+    When every predictor exposes its fitted model (``fitted``, as a
+    fitted ``C3OPredictor`` does), the whole grid is one device program
+    (``predict_grid``): the rows are copied once, every machine's model
+    scores them, and one transfer brings the [M, S*C] result back.
+    Otherwise (predictors with only ``predict``) each machine's grid is
+    dispatched on its own, all before the first host sync.  Returns
+    (machine names, t [M, C, S]) with runtimes clamped at >= 0 (a
+    negative runtime would make a negative cost win every
+    cheapest-choice selection downstream)."""
     contexts = np.atleast_2d(np.asarray(contexts, np.float64))
     rows = grid_rows(scaleouts, contexts)
-    names, pending = [], []
-    for m, pred in predictors.items():
-        names.append(m)
-        pending.append(_predict_rows(pred, rows))           # async dispatch
-    t = np.stack([to_host(p).reshape(len(scaleouts), len(contexts)).T
-                  for p in pending])
-    return names, np.maximum(t, 0.0)
+    names = list(predictors)
+    models = [getattr(p, "fitted", None) for p in predictors.values()]
+    if all(m is not None for m in models):
+        t = to_host(predict_grid(models, rows))
+    else:
+        pending = [_predict_rows(p, rows) for p in predictors.values()]
+        t = np.stack([to_host(p) for p in pending])
+    t = t.reshape(len(names), len(scaleouts), len(contexts))
+    return names, np.maximum(t.transpose(0, 2, 1), 0.0)
 
 
 def machine_grid_costs(predictors: Dict[str, object],
@@ -530,10 +593,9 @@ def machine_grid_costs(predictors: Dict[str, object],
                        scaleouts: Sequence[int],
                        contexts: np.ndarray
                        ) -> Tuple[List[str], np.ndarray, np.ndarray]:
-    """Score the full (machine x scale-out x context) grid.
-
-    Dispatches every machine's grid prediction before the first host sync;
-    returns (machine names, t [M, C, S], cost [M, C, S])."""
+    """Score the full (machine x scale-out x context) grid through
+    ``machine_grid_runtimes``; returns (machine names, t [M, C, S],
+    cost [M, C, S])."""
     names, t = machine_grid_runtimes(predictors, scaleouts, contexts)
     S = np.asarray(scaleouts, np.float64)
     cost = np.stack([prices[m] for m in names])[:, None, None] \

@@ -138,9 +138,15 @@ class C3OPredictor:
             state["params"])
         return pred
 
+    @property
+    def fitted(self):
+        """The selected model as fitted (a ``FittedModel``: ``spec``,
+        ``params``, ``aux``), None before ``fit``: a machine grid scores
+        every machine's in one device program through it."""
+        return getattr(self, "_fitted", None)
+
     def predict_device(self, X) -> jax.Array:
-        """Device-resident batched prediction (no host sync); grid sweeps
-        use this to pipeline dispatches across predictors."""
+        """Device-resident batched prediction (no host sync)."""
         return self._fitted.predict_device(np.asarray(X, np.float64))
 
     def predict(self, X) -> np.ndarray:

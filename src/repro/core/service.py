@@ -7,9 +7,9 @@ then ``Configurator.choose_scaleout``) approximates it with two separate
 calls and cannot see deadline interactions across machines.
 ``ConfigurationService.choose_cluster_batch`` scores the full
 (machine x scale-out x context) grid through ``engine.machine_grid_costs``
-— every machine's grid prediction is dispatched before the first host sync,
-no per-machine Python-loop syncs — then selects machine and scale-out
-simultaneously with vectorized numpy:
+— one device program for every machine's model, one transfer back (a
+dispatch per machine where a predictor exposes no fitted model) — then
+selects machine and scale-out simultaneously with vectorized numpy:
 
     deadline given:  cheapest (m, s) whose runtime bound meets the deadline
                      (clean options first, bottlenecked fallback, then the
@@ -45,7 +45,9 @@ class ConfigurationService:
     these deadlines" over per-machine-type predictors.
 
     Predictors must expose ``predict``/``predict_device`` plus the CV error
-    calibration attributes ``mu``/``sigma`` (``C3OPredictor`` does)."""
+    calibration attributes ``mu``/``sigma`` (``C3OPredictor`` does); where
+    each also exposes ``fitted``, as a fitted ``C3OPredictor`` does, the
+    grid is scored in one device program."""
 
     predictors: Dict[str, object]                # machine type -> predictor
     prices: Dict[str, float]                     # $ per node-hour
@@ -87,9 +89,11 @@ class ConfigurationService:
     def score_cluster_grid(self, contexts: np.ndarray):
         """(machine names, t, bound, cost, bottleneck), arrays [M, C, S].
 
-        One engine dispatch: every machine's grid prediction is enqueued
-        before the first host sync; runtimes are clamped at >= 0 so a model
-        extrapolating negative can never yield a cost that wins selection."""
+        One engine dispatch and one host sync when every predictor
+        exposes its fitted model (``engine.machine_grid_runtimes``), else
+        one per machine, all enqueued before the first sync; runtimes are
+        clamped at >= 0 so a model extrapolating negative can never yield
+        a cost that wins selection."""
         contexts = np.atleast_2d(np.asarray(contexts, np.float64))
         names, t, cost = engine.machine_grid_costs(
             self.predictors, self.prices, self.scaleouts, contexts)

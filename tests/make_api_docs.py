@@ -53,7 +53,8 @@ _SPANS = (
      "a choose tick"),
     ("service.select", "choosing from the scored grid and building the "
      "choices"),
-    ("engine.dispatch", "one predictor's device enqueue"),
+    ("engine.dispatch", "one device program's enqueue: a predictor's, "
+     "or a choose tick's whole machine grid"),
     ("engine.sync", "waiting for one device result on the host"),
     ("engine.cv", "a predictor's LOO-CV model selection"),
     ("engine.fit", "a predictor's final fit"),

@@ -171,3 +171,120 @@ def test_gbm_kernel_routing_matches_jnp_path(monkeypatch):
     out = fm.predict(Xq)
     assert calls, "GBM prediction on a TPU backend must take the kernel"
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+
+
+# --------------------------------------------------------------------------
+# machine grid: one device program for every machine's model
+# --------------------------------------------------------------------------
+
+POOL = ("ernest", "gbm", "bom", "ogb")
+MACHINES = list(W.MACHINES)
+
+
+class _Unfitted:
+    """A predictor that exposes no fitted model: only ``predict``,
+    ``predict_device`` and its error calibration, so a grid scores it on
+    its own."""
+
+    def __init__(self, pred):
+        self._pred = pred
+        self.mu, self.sigma = pred.mu, pred.sigma
+
+    def predict_device(self, X):
+        return self._pred.predict_device(X)
+
+    def predict(self, X):
+        return self._pred.predict(X)
+
+
+@pytest.fixture(scope="module")
+def pool_predictors():
+    """grep's predictor on every machine type for every pool model, each
+    fitted with that model alone, so it is the one selected."""
+    d = W.generate_job_data("grep")
+    out = {}
+    for m in MACHINES:
+        dm = d.filter_machine(m)
+        for name in POOL:
+            out[m, name] = C3OPredictor(model_names=(name,),
+                                        max_cv_folds=15).fit(dm.X, dm.y)
+    return out
+
+
+def _hub(pool_predictors, first):
+    """Machines selecting different models: ``first`` on the first
+    machine, the pool's next ones on the others."""
+    k = POOL.index(first)
+    return {m: pool_predictors[m, POOL[(k + i) % len(POOL)]]
+            for i, m in enumerate(MACHINES)}
+
+
+def _grid_contexts():
+    rng = np.random.default_rng(7)
+    return np.stack([rng.uniform(10, 20, 5),
+                     rng.choice([.002, .02, .08], 5)], axis=1)
+
+
+def _counts(rec):
+    """Device round trips a recorder saw (lowerings and compiles left
+    out: a first call also compiles)."""
+    return {name: count for name, count, _, _ in rec.snapshot()
+            if name in ("engine.dispatch", "engine.sync")}
+
+
+@pytest.mark.parametrize("first", POOL)
+def test_machine_grid_is_one_program_matching_per_predictor_path(
+        pool_predictors, first):
+    from repro.core import trace
+    hub = _hub(pool_predictors, first)
+    contexts = _grid_contexts()
+    with trace.recording(trace.Recorder()) as fused_rec:
+        names, t = engine.machine_grid_runtimes(hub, SCALEOUTS, contexts)
+    with trace.recording(trace.Recorder()) as own_rec:
+        names_own, t_own = engine.machine_grid_runtimes(
+            {m: _Unfitted(p) for m, p in hub.items()}, SCALEOUTS, contexts)
+    assert names == names_own == MACHINES
+    assert t.shape == (len(MACHINES), len(contexts), len(SCALEOUTS))
+    np.testing.assert_array_equal(t, t_own)
+    assert _counts(fused_rec) == {"engine.dispatch": 1, "engine.sync": 1}
+    assert _counts(own_rec) == {"engine.dispatch": len(MACHINES),
+                                "engine.sync": len(MACHINES)}
+
+
+def test_choose_cluster_batch_is_one_dispatch_and_one_sync(
+        pool_predictors):
+    from repro.core import trace
+    from repro.core.service import ConfigurationService
+    hub = _hub(pool_predictors, "gbm")
+    contexts = _grid_contexts()
+    t_max = np.array([300.0, np.nan, 150.0, 600.0, 1e9])
+    with trace.recording(trace.Recorder()) as rec:
+        fused = ConfigurationService(hub, PRICES, SCALEOUTS)\
+            .choose_cluster_batch(contexts, t_max)
+    own = ConfigurationService({m: _Unfitted(p) for m, p in hub.items()},
+                               PRICES, SCALEOUTS)
+    for got, want in zip(fused, own.choose_cluster_batch(contexts, t_max),
+                         strict=True):
+        assert got == want
+    counts = _counts(rec)
+    assert counts["engine.dispatch"] == counts["engine.sync"] == 1
+
+
+def test_machine_grid_falls_back_per_predictor_without_a_fitted_model(
+        pool_predictors):
+    from repro.core import trace
+    hub = _hub(pool_predictors, "ernest")
+    contexts = _grid_contexts()
+    _, fused = engine.machine_grid_runtimes(hub, SCALEOUTS, contexts)
+    fake = _FakePredictor()
+    mixed = dict(hub, **{MACHINES[0]: fake})
+    with trace.recording(trace.Recorder()) as rec:
+        names, t = engine.machine_grid_runtimes(mixed, SCALEOUTS, contexts)
+    assert names == MACHINES
+    want = fake.predict(engine.grid_rows(SCALEOUTS, contexts))
+    np.testing.assert_array_equal(
+        t[0], want.reshape(len(SCALEOUTS), len(contexts)).T)
+    np.testing.assert_array_equal(t[1:], fused[1:])
+    # the fake answers on the host: only the fitted machines dispatch
+    assert _counts(rec) == {"engine.dispatch": len(MACHINES) - 1,
+                            "engine.sync": len(MACHINES)}

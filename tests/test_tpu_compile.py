@@ -5,16 +5,21 @@ The TPU compiler refuses what interpret mode and the CPU backend accept
 engine's device programs against a described ``v5e:2x2`` topology:
 
 - the Pallas GBM kernel, as the engine serves it, at 24 to 4096 rows;
+- the machine grid program (``grid_executable``) with GBM-selected
+  machines, whose kernel calls keep the ``run.<n>`` names a device trace
+  finds the kernel by;
 - ``cv_executable`` for every pool model on one chip at a real store size;
 - ``cv_executable_sharded`` over the four described chips, with its
   fold-weight buffer donated as on a TPU host.
 
-Nothing runs, so nothing here says anything about results or times.  The
+Nothing runs, so nothing here says anything about results or times (one
+CPU case runs the grid program's kernel in interpret mode).  The
 topology is described inside a fixture (never at import), and the
 persistent compilation cache is off around the compiles: a program
 compiled for a chip that is not attached cannot be read back from it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,3 +127,71 @@ def test_cv_executable_sharded_compiles_for_v5e_2x2(topo, store, model,
     lowered = engine.cv_executable_sharded(spec, 4).lower(*args)
     assert lowered.args_info[0][2].donated
     assert "all-reduce" in lowered.compile().as_text()
+
+
+#: a compiled Pallas call: its HLO instruction name
+_KERNEL_CALL = re.compile(r"%?([\w.\-]+) = .*custom_call_target="
+                          r"\"tpu_custom_call\"")
+
+
+def _kernel_names(compiled) -> list:
+    return [m.group(1) for m in map(_KERNEL_CALL.search,
+                                    compiled.as_text().splitlines()) if m]
+
+
+@pytest.mark.parametrize("models", [("gbm", "gbm", "ernest"),
+                                    ("ernest", "gbm", "bom", "ogb")])
+def test_grid_program_compiles_for_v5e_with_run_kernel_names(
+        one_chip, store, models):
+    """The trace reduction finds the GBM kernel by instruction names
+    that start with ``run.``: inside the grid program as alone."""
+    X, y, folds, w = store
+    specs = tuple(get_model(m) for m in models)
+    aux = tuple(spec.make_aux(X) for spec in specs)
+    shapes = tuple(jax.eval_shape(spec.fit, X.astype(np.float32),
+                                  y.astype(np.float32),
+                                  w.astype(np.float32), a)
+                   for spec, a in zip(specs, aux))
+    params = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes)
+    rows = np.zeros((len(W.MACHINES) * 7 * 4, X.shape[1]), np.float32)
+    args = _abstract((params, aux, rows), one_chip)
+    names = _kernel_names(engine.grid_executable(specs, kernel=True)
+                          .lower(*args).compile())
+    assert len(names) == models.count("gbm")
+    assert all(n.startswith("run.") for n in names), names
+    alone = _abstract((rows, *(params[models.index("gbm")][1:4]),
+                       np.float32(0), np.float32(0)), one_chip)
+    names = _kernel_names(engine._gbm_kernel_executable()
+                          .lower(*alone).compile())
+    assert names == ["run.1"]
+
+
+def test_grid_program_runs_the_interpreted_kernel_like_the_executable():
+    """CPU: the kernel traced into the grid program (interpret mode)
+    answers as the standalone kernel executable, next to a jnp model."""
+    from repro.core.models.api import FittedModel
+    from repro.core.models.gbm import GBMParams
+    rng = np.random.default_rng(3)
+    d = W.generate_job_data("grep").filter_machine("m5.xlarge")
+    ernest = FittedModel(get_model("ernest"), d.X, d.y)
+    T, n_int, k = 32, 7, d.X.shape[1]
+
+    def gbm(y_scale):
+        return GBMParams(np.float32(rng.normal()),
+                         rng.integers(0, k, (T, n_int)).astype(np.int32),
+                         rng.uniform(0, 20, (T, n_int)).astype(np.float32),
+                         rng.normal(0, 0.1, (T, n_int + 1))
+                         .astype(np.float32), np.float32(y_scale))
+
+    spec = get_model("gbm")
+    params = (gbm(0.0), ernest.params, gbm(3.0))
+    X = engine.grid_rows([2, 4, 8], d.X[:5, 1:]).astype(np.float32)
+    got = engine.grid_executable((spec, ernest.spec, spec), kernel=True,
+                                 interpret=True)(
+        params, ({}, ernest.aux, {}), X)
+    kernel = engine._gbm_kernel_executable(interpret=True)
+    for i in (0, 2):
+        np.testing.assert_array_equal(got[i], kernel(X, *params[i][1:4],
+                                                     params[i].f0,
+                                                     params[i].y_scale))
+    np.testing.assert_array_equal(got[1], ernest.predict_device(X))

@@ -216,8 +216,9 @@ def test_edge_in_flight_and_idle_leave_out_stats_and_healthz(gw):
         == _rows(snaps[-2])["edge.in_flight"][0]
     lanes = {ln.lane: _rows(ln.spans) for ln in stats.lanes}
     assert lanes["grep"]["lane.tick"][0] == 1
-    assert lanes["grep"]["engine.dispatch"][0] == len(W.MACHINES)
-    assert lanes["grep"]["engine.sync"][0] == len(W.MACHINES)
+    # a choose tick scores every machine's grid in one device program
+    assert lanes["grep"]["engine.dispatch"][0] == 1
+    assert lanes["grep"]["engine.sync"][0] == 1
     assert lanes["grep@m5.xlarge"]["engine.sync"][0] == 1
 
 
